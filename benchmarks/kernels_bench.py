@@ -66,7 +66,8 @@ def bench_local(ks=(4, 8), tau=1, batch=8, iters=5, probes=3):
       and HVP share one ``jax.linearize`` and all k moment/parameter
       updates run as one batched jnp expression. This isolates the
       structural win; it is bit-exact with ``plain``.
-    - ``fused_pallas_interp`` — the same structure through the batched
+    - ``fused_pallas_interp`` (``fused_pallas`` on a TPU, where the kernel
+      is compiled) — the same structure through the batched
       Pallas kernel in interpret mode. On CPU the interpreter's per-op
       dispatch dominates at CNN scale, so this row records the honest
       interpret-mode *overhead* (the kernel targets TPU); the fused-path
@@ -93,8 +94,10 @@ def bench_local(ks=(4, 8), tau=1, batch=8, iters=5, probes=3):
     """
     from repro.configs.base import ElasticConfig, OptimizerConfig, get_config
     from repro.core.coordinator import ElasticTrainer
+    from repro.kernels import interpret_mode
     from repro.models.registry import build_model
 
+    interpret = interpret_mode()
     model = build_model(get_config("paper_cnn"))
     record = {"what": "local", "arch": "paper-cnn", "tau": tau,
               "batch_size": batch, "iters": iters, "ks": list(ks),
@@ -110,14 +113,15 @@ def bench_local(ks=(4, 8), tau=1, batch=8, iters=5, probes=3):
             "labels": jnp.zeros((tau, k, batch), jnp.int32),
         }
         rng = jax.random.key(1)
+        pallas = "fused_pallas_interp" if interpret else "fused_pallas"
         variants = (("plain", {}), ("fused_jnp", {"fused_local": True}),
-                    ("fused_pallas_interp", {"use_pallas": True}))
+                    (pallas, {"use_pallas": True}))
         for label, kw in variants:
             tr = ElasticTrainer(model, ocfg, ecfg, **kw)
             state = tr.init_state(jax.random.key(0))
             f = jax.jit(
                 lambda s, b, r, t=tr: t.local_phase(s, b, r)[0]["workers"])
-            if "pallas" in label:  # interpret mode: seconds/call, 1 probe
+            if label == "fused_pallas_interp":  # seconds/call, 1 probe
                 us = _time(f, state, batches, rng, iters=2)
             else:  # CPU noise guard, as in bench_comm_modes
                 us = min(_time(f, state, batches, rng, iters=iters)
@@ -161,6 +165,7 @@ def bench_local(ks=(4, 8), tau=1, batch=8, iters=5, probes=3):
 def bench():
     rows = []
     from repro.core.elastic import elastic_update
+    from repro.kernels import interpret_mode
     from repro.kernels.elastic.ops import elastic_update_pallas
 
     tree = {"w": jax.random.normal(jax.random.key(0), (1024, 1024))}
@@ -168,9 +173,15 @@ def bench():
     f_jnp = jax.jit(lambda w, m: elastic_update(w, m, 0.1, 0.1))
     us = _time(f_jnp, tree, mtree)
     rows.append(("elastic_update_jnp_1M", us, f"{8 * 2 ** 20 / us:.0f}B/us"))
-    f_pal = lambda w, m: elastic_update_pallas(w, m, 0.1, 0.1)
+    interpret = interpret_mode()
+    f_pal = lambda w, m: elastic_update_pallas(w, m, 0.1, 0.1,
+                                               interpret=interpret)
     us = _time(f_pal, tree, mtree)
-    rows.append(("elastic_update_pallas_interp_1M", us, "interpret-mode"))
+    if interpret:
+        rows.append(("elastic_update_pallas_interp_1M", us, "interpret-mode"))
+    else:
+        rows.append(("elastic_update_pallas_1M", us,
+                     f"{8 * 2 ** 20 / us:.0f}B/us"))
 
     from repro.configs.base import OptimizerConfig
     from repro.kernels.adahessian.ref import adahessian_step_ref

@@ -29,6 +29,7 @@ per-round comm time drops as global sub-master↔master syncs amortize over
 no-worse-than-flat session comparison."""
 import argparse
 import json
+import sys
 
 
 def main(argv=None) -> None:
@@ -39,6 +40,9 @@ def main(argv=None) -> None:
                              "membership", "control", "serving",
                              "scenarios", "hierarchy"])
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.what == "local":
         from benchmarks import kernels_bench
@@ -105,14 +109,18 @@ def main(argv=None) -> None:
         sections.append(("session", session_bench.bench))
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in sections:
         try:
             rows = fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
             print(f"{name},0,ERROR:{type(e).__name__}:{e}")
+            failed.append(name)
             continue
         for row_name, us, derived in rows:
             print(f"{row_name},{us:.1f},{derived}")
+    if failed:
+        sys.exit(f"benchmark section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

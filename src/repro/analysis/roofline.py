@@ -1,4 +1,4 @@
-"""Roofline terms from dry-run artifacts (TPU v5e-class target).
+"""Roofline terms from dry-run artifacts, against the peaks of one chip.
 
     compute term    = HLO_FLOPs_global    / (chips × peak_FLOP/s)
     memory term     = HLO_bytes_global    / (chips × HBM_bw)
@@ -19,9 +19,42 @@ from typing import Dict, Optional
 
 from repro.configs.base import INPUT_SHAPES, ModelConfig, get_config
 
-PEAK_FLOPS = 197e12       # bf16 FLOP/s per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float   # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # chip-to-chip bytes/s per link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``. A device not listed here is an error
+# (``peaks``), never a default.
+PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interchip interconnect over 4 links
+        ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI"),
+}
+
+# The chip the dry-run meshes model (v5e pods of 256 chips); dry-run
+# records carry it as ``target_device_kind``.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Published peaks of one chip of ``device_kind``; raises for a kind
+    with no row in :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind={device_kind!r}; add a "
+            f"row with its source to repro.analysis.roofline.PEAKS "
+            f"(known: {sorted(PEAKS)})") from None
 
 
 def active_param_count(cfg: ModelConfig) -> int:
@@ -100,10 +133,11 @@ def roofline_from_record(rec: Dict) -> Optional[Roofline]:
     mf = model_flops(cfg, rec["shape"], rec.get("lowered_kind", "train"))
     # multi-pod elastic round trains k workers' sub-batches = same global D
     hlo_global = flops_d * n
+    pk = peaks(rec["target_device_kind"])
     return Roofline(
-        compute_s=flops_d / PEAK_FLOPS,
-        memory_s=bytes_d / HBM_BW,
-        collective_s=coll_d / ICI_BW,
+        compute_s=flops_d / pk.flops,
+        memory_s=bytes_d / pk.hbm_bw,
+        collective_s=coll_d / pk.ici_bw,
         model_flops=mf,
         hlo_flops_global=hlo_global,
         flops_ratio=(mf / hlo_global) if hlo_global else None,

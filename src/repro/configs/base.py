@@ -10,7 +10,8 @@ import dataclasses
 import importlib
 from typing import Optional, Tuple
 
-import jax.numpy as jnp
+import ml_dtypes  # noqa: F401 — registers bfloat16 & co. with numpy
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,11 +86,11 @@ class ModelConfig:
 
     @property
     def adtype(self):
-        return jnp.dtype(self.dtype)
+        return np.dtype(self.dtype)
 
     @property
     def pdtype(self):
-        return jnp.dtype(self.param_dtype)
+        return np.dtype(self.param_dtype)
 
     @property
     def moe(self) -> bool:

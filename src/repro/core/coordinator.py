@@ -46,11 +46,9 @@ Placement (``ecfg.placement``) picks where the k workers live:
   ``comm_mode="fused"``: the sequential backend is an event-ordered scan
   where each worker reads the master the previous one wrote, a serial
   dependency that cannot be placed on disjoint shards. Any extra mesh axes
-  ('data', 'model') are currently *replicated* inside the sharded round —
-  fully-manual shard_map; leaving them in the ``auto`` set so GSPMD shards
-  each worker's model within its pod is the intended endgame, but this
-  XLA version's partitioner aborts on partial-auto transformer bodies
-  (see ``_round_sharded``). The production multi-pod lowering in
+  ('data', 'model') are currently *replicated* inside the sharded round,
+  whose ``jax.shard_map`` is fully manual (see ``_round_sharded``). The
+  production multi-pod lowering in
   repro/launch/dryrun.py reuses exactly these entry points.
 
 Both placements run the same ``_round`` body; the sharded path threads the
@@ -349,6 +347,7 @@ class ElasticTrainer:
         kernel on the single-device path (interpret mode on CPU), the
         bitwise-identical vmapped jnp expression per shard under sharded
         placement (mirroring the elastic comm kernel's gating)."""
+        from repro.kernels import interpret_mode
         from repro.kernels.adahessian.ops import adahessian_update_batched
 
         if axis is not None and k_loc == 1:
@@ -368,7 +367,7 @@ class ElasticTrainer:
         new_p, new_o = adahessian_update_batched(
             params, grads, hs, opt_state, self.opt_cfg,
             use_kernel=self.use_pallas and axis is None,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
         return new_p, new_o, loss
 
     def local_phase(self, state, batches, rng, straggle=None, active=None,
@@ -564,11 +563,11 @@ class ElasticTrainer:
             w1 = jnp.where(dead_i, 0.0, w1)
             w2 = jnp.where(dead_i, 0.0, w2)
             if self.use_pallas:
+                from repro.kernels import interpret_mode
                 from repro.kernels.elastic.ops import elastic_update_pallas
 
                 new_w, new_master = elastic_update_pallas(
-                    w_i, master, w1, w2,
-                    interpret=jax.default_backend() != "tpu")
+                    w_i, master, w1, w2, interpret=interpret_mode())
             else:
                 new_w, new_master = elastic_update(w_i, master, w1, w2)
             if active is not None:  # vacant slots report zeroed diagnostics
@@ -661,11 +660,12 @@ class ElasticTrainer:
         # workers_in == state["workers"] unless the score_clip quarantine
         # re-seated a diverged slot above
         if self.use_pallas and axis is None:
+            from repro.kernels import interpret_mode
             from repro.kernels.elastic.ops import elastic_update_batched_pallas
 
             workers, master = elastic_update_batched_pallas(
                 workers_in, master, w1, g2, master_ref=master_ref,
-                interpret=jax.default_backend() != "tpu")
+                interpret=interpret_mode())
         else:
             workers, master = elastic_update_batched(
                 workers_in, master, w1, g2, axis_name=axis,
@@ -933,22 +933,17 @@ class ElasticTrainer:
         R-round scan) over the mesh, fully manual. Specs mention only the
         'pod' axis, so any 'data'/'model' axes replicate the per-worker
         computation — exactly equivalent on the size-1 host-mesh axes.
-        (Leaving those axes in ``shard_map``'s ``auto`` set so GSPMD shards
-        each worker's model *within* its pod is the intended production
-        endgame, but this jax/XLA version's SPMD partitioner hard-aborts on
-        partial-auto transformer bodies — hlo_sharding_util
-        ``IsManualSubgroup`` check — so within-pod model sharding waits on
-        an XLA upgrade.)"""
-        from jax.experimental.shard_map import shard_map
-
+        The partial-manual form (``axis_names=``), which would let GSPMD
+        shard each worker's model *within* its pod, has not been tried on
+        the installed jax 0.9.0."""
         state_spec, in_spec, met_spec = self._shard_specs(inputs, chunk)
         step = functools.partial(self._round, axis=POD_AXIS)
         body = (lambda s, i: jax.lax.scan(step, s, i)) if chunk else step
-        fn = shard_map(
-            body, self.mesh,
+        fn = jax.shard_map(
+            body, mesh=self.mesh,
             in_specs=(state_spec, in_spec),
             out_specs=(state_spec, met_spec),
-            check_rep=False)
+            check_vma=False)
         return fn(state, inputs)
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)

@@ -50,11 +50,15 @@ def elastic_update_batched(worker_stacked, master_params, w1, w2,
     With ``axis_name`` (sharded placement, inside ``shard_map``): the leading
     axis holds only this shard's k/n_pods workers and the master reduction
     becomes a cross-pod collective. The worker pull stays shard-local; the
-    weighted diffs are all-gathered along the worker axis and reduced with
-    the *same* (k, ...)-shaped sum as the single-device path — an all-reduce
-    decomposed as all-gather + local reduction — so the sharded master is
-    bit-exact with the single-device fused master (a ``psum`` of per-shard
-    partial sums would differ in the last ulp from re-associating the sum).
+    diffs and the (k,) weights are all-gathered along the worker axis and
+    the master reduction is the *same* (k, ...)-shaped weighted sum as the
+    single-device path — an all-reduce decomposed as all-gather + local
+    reduction — so the sharded master is bit-exact with the single-device
+    fused master. (A ``psum`` of per-shard partial sums would re-associate
+    the sum; gathering the products ``w2·diff`` instead of the diffs would
+    let the compiler fuse the multiply into the reduction on one path and
+    not the other, which differs in the last ulp once it contracts
+    multiply-adds.)
 
     ``master_ref`` (optional pytree like the master): delayed averaging
     (DaSGD / ``ElasticConfig.staleness``) — every diff θ^i − θ^ref is
@@ -70,6 +74,8 @@ def elastic_update_batched(worker_stacked, master_params, w1, w2,
     """
     w1 = jnp.asarray(w1, jnp.float32)
     w2 = jnp.asarray(w2, jnp.float32)
+    if axis_name is not None:
+        w2 = jax.lax.all_gather(w2, axis_name, axis=0, tiled=True)
 
     def upd(ws, m, ref=None):
         h1 = w1.reshape((-1,) + (1,) * (ws.ndim - 1))
@@ -78,11 +84,10 @@ def elastic_update_batched(worker_stacked, master_params, w1, w2,
         mf = m.astype(jnp.float32)
         diff = wf - (mf[None] if ref is None
                      else ref.astype(jnp.float32)[None])
-        pull = h2 * diff
+        new_w = (wf - h1 * diff).astype(ws.dtype)
         if axis_name is not None:
-            pull = jax.lax.all_gather(pull, axis_name, axis=0, tiled=True)
-        return ((wf - h1 * diff).astype(ws.dtype),
-                (mf + jnp.sum(pull, axis=0)).astype(m.dtype))
+            diff = jax.lax.all_gather(diff, axis_name, axis=0, tiled=True)
+        return new_w, (mf + jnp.sum(h2 * diff, axis=0)).astype(m.dtype)
 
     if master_ref is None:
         pairs = jax.tree.map(upd, worker_stacked, master_params)
@@ -165,21 +170,21 @@ def elastic_update_grouped(worker_stacked, submasters, w1, w2, grp,
 
     if balanced:
         s = cap // n_groups
+        w2_all = jax.lax.all_gather(w2, axis_name, axis=0, tiled=True)
 
         def upd(ws, sm):
             h1 = w1.reshape((-1,) + (1,) * (ws.ndim - 1))
-            h2 = w2.reshape((-1,) + (1,) * (ws.ndim - 1))
+            h2 = w2_all.reshape((n_groups, s) + (1,) * (ws.ndim - 1))
             wf = ws.astype(jnp.float32)
             smf = sm.astype(jnp.float32)
             diff = wf - jnp.take(smf, grp_local, axis=0)
-            push = jax.lax.all_gather(h2 * diff, axis_name, axis=0,
-                                      tiled=True)
-            # identical values and reduction tree as the single-device
-            # branch: reshape the full push to (G, k/G, ...) and reduce
-            acc = jnp.sum(push.reshape((n_groups, s) + push.shape[1:]),
-                          axis=1)
-            return ((wf - h1 * diff).astype(ws.dtype),
-                    (smf + acc).astype(sm.dtype))
+            new_w = (wf - h1 * diff).astype(ws.dtype)
+            # identical values and expression as the single-device branch:
+            # gather the diffs (not the pushes, for the reason given in
+            # elastic_update_batched), reshape to (G, k/G, ...) and reduce
+            diff = jax.lax.all_gather(diff, axis_name, axis=0, tiled=True)
+            diff = diff.reshape((n_groups, s) + diff.shape[1:])
+            return new_w, (smf + jnp.sum(h2 * diff, axis=1)).astype(sm.dtype)
 
         return _unzip_pairs(jax.tree.map(upd, worker_stacked, submasters))
 

@@ -1,6 +1,9 @@
 """Paper figs 4–5 grid driver: 6 methods × k∈{4,8} × τ∈{1,2,4} (+ seeds),
 run as a bounded pool of subprocesses (XLA-CPU underutilizes cores for this
-model size, so process-level parallelism ≈ free wall-clock).
+model size, so process-level parallelism ≈ free wall-clock). On an
+accelerator every child needs the chip, which one process holds at a time,
+so the pool runs one child at a time unless ``JAX_PLATFORMS=cpu``; the
+parent itself never imports JAX.
 
 Also fig 3: overlap-ratio sweep {0, .125, .25, .375, .5} on EAHES-O, and a
 beyond-paper scenario axis (``--what scenarios``): every failure regime from
@@ -37,7 +40,11 @@ def job_cmd(method, k, tau, seed, rounds, out, overlap=None, scenario=None,
 
 def run_pool(jobs, max_procs=5):
     """Run jobs as a bounded subprocess pool; returns the list of failed job
-    names (empty when everything exited 0)."""
+    names (empty when everything exited 0). ``max_procs`` applies only
+    under ``JAX_PLATFORMS=cpu``; otherwise the children share one chip and
+    run one at a time."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        max_procs = 1
     procs = []
     t0 = time.time()
     pending = list(jobs)

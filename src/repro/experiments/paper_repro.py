@@ -29,7 +29,6 @@ from typing import Optional
 
 import numpy as np
 
-from repro.api import ElasticSession, RunSpec
 from repro.configs.base import (FAILURE_SCENARIOS, ElasticConfig,
                                 OptimizerConfig)
 
@@ -72,6 +71,9 @@ def run_one(
     byzantine_frac: float = 0.25,
     byzantine_mode: str = "sign_flip",
 ):
+    # imported here so the grid driver can list METHODS without JAX
+    from repro.api import ElasticSession, RunSpec
+
     opt_name, dynamic, oracle, use_overlap = METHODS[method]
     r = (overlap_ratio if overlap_ratio is not None
          else (paper_overlap_ratio(k) if use_overlap else 0.0))

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 BLOCK_ROWS = 256
 LANES = 128
 
@@ -57,7 +59,7 @@ def _kernel(s_ref, p_ref, g_ref, h_ref, m_ref, v_ref,
 @functools.partial(
     jax.jit, static_argnames=("interpret", "block_rows"))
 def adahessian_update_flat(
-    p, g, h, m, v, scalars, *, interpret: bool = True,
+    p, g, h, m, v, scalars, *, interpret: bool | None = None,
     block_rows: int = BLOCK_ROWS,
 ):
     """All arrays (rows, 128); scalars (7,) f32 = lr,b1,b2,bc1,bc2,κ/2,ε."""
@@ -75,7 +77,7 @@ def adahessian_update_flat(
             jax.ShapeDtypeStruct(m.shape, jnp.float32),
             jax.ShapeDtypeStruct(v.shape, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(scalars, p, g, h, m, v)
     return out
 
@@ -125,7 +127,7 @@ def batched_block_rows(k: int, block_rows: int = BLOCK_ROWS) -> int:
 def adahessian_update_batched_flat(
     p, g, h, m, v, bc1, bc2, *, lr: float, b1: float, b2: float,
     denom_pow: float, eps: float, lrwd: float = 0.0,
-    interpret: bool = True, block_rows: int | None = None,
+    interpret: bool | None = None, block_rows: int | None = None,
 ):
     """All data arrays (k, rows, 128); bc1/bc2 (k,) f32 per-worker bias
     corrections (the only runtime scalars — everything else is a static
@@ -151,6 +153,7 @@ def adahessian_update_batched_flat(
             jax.ShapeDtypeStruct(m.shape, jnp.float32),
             jax.ShapeDtypeStruct(v.shape, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="adahessian_update_batched",
     )(bc, p, g, h, m, v)
     return out

@@ -25,7 +25,7 @@ def pack_scalars(cfg: OptimizerConfig, t: jax.Array) -> jax.Array:
 
 
 def adahessian_step_pallas(p, g, h, m, v, cfg: OptimizerConfig, t,
-                           *, interpret: bool = True):
+                           *, interpret: bool | None = None):
     """p,g,h,m,v: 1-D same-length f32 arrays (pre-flattened). Returns
     (p', m', v') with padding handled internally."""
     n = p.shape[0]
@@ -44,7 +44,7 @@ def adahessian_step_pallas(p, g, h, m, v, cfg: OptimizerConfig, t,
 def adahessian_update_batched(worker_params, grads, hs, opt_state,
                               cfg: OptimizerConfig, *,
                               use_kernel: bool = True,
-                              interpret: bool = True):
+                              interpret: bool | None = None):
     """Batched AdaHessian step for all k workers in one pass (ISSUE-7).
 
     ``worker_params`` / ``grads`` / ``hs`` are stacked pytrees with a

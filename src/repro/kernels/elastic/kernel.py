@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 BLOCK_ROWS = 256
 LANES = 128
 
@@ -42,7 +44,7 @@ def elastic_update_flat(
     h1: jax.Array,
     h2: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_rows: int = BLOCK_ROWS,
 ) -> tuple:
     """w, m: (rows, 128) — rows must be a multiple of ``block_rows``."""
@@ -63,7 +65,7 @@ def elastic_update_flat(
             jax.ShapeDtypeStruct(w.shape, w.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(h, w, m)
     return out[0], out[1]
 
@@ -111,7 +113,7 @@ def elastic_update_batched_flat(
     h2: jax.Array,
     ref: jax.Array | None = None,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_rows: int | None = None,
 ) -> tuple:
     """w: (k, rows, 128) stacked workers; m: (rows, 128); h1/h2: (k,).
@@ -149,6 +151,7 @@ def elastic_update_batched_flat(
             jax.ShapeDtypeStruct(w.shape, w.dtype),
             jax.ShapeDtypeStruct(m.shape, m.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="elastic_update_batched",
     )(*operands)
     return out[0], out[1]

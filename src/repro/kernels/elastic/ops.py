@@ -20,7 +20,7 @@ _unflatten_stacked = unflatten_stacked
 
 
 def elastic_update_pallas(worker_params, master_params, h1, h2, *,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Fused eqs. (12)–(13) over whole pytrees. Returns (worker', master')."""
     wf, wl, wd, n = flatten_tree(worker_params, BLOCK_ROWS)
     mf, ml, md, _ = flatten_tree(master_params, BLOCK_ROWS)
@@ -30,7 +30,8 @@ def elastic_update_pallas(worker_params, master_params, h1, h2, *,
 
 
 def elastic_update_batched_pallas(worker_stacked, master_params, h1, h2, *,
-                                  master_ref=None, interpret: bool = True):
+                                  master_ref=None,
+                                  interpret: bool | None = None):
     """All k worker exchanges + the h2-weighted master reduction in one
     kernel pass. ``worker_stacked`` leaves carry a leading (k,) axis; h1/h2
     are (k,) vectors (pass ``master_schedule_weights(h2)`` for event-order

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -104,7 +106,7 @@ def flash_attention(
     chunk=None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
@@ -141,6 +143,6 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),       # l (running denom)
             pltpu.VMEM((block_q, D), jnp.float32),     # acc
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, D)

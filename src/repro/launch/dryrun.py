@@ -17,6 +17,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.analysis.hlo import collective_bytes  # noqa: E402
+from repro.analysis.roofline import DRYRUN_DEVICE_KIND  # noqa: E402
 from repro.configs.base import (INPUT_SHAPES, OptimizerConfig,  # noqa: E402
                                 get_config, list_archs, normalize_arch,
                                 shape_supported)
@@ -72,8 +73,7 @@ def _abstract_pod(spec_tree, mesh, pod_dim=0):
     ``pod_dim`` (the worker axis) and replicated elsewhere — the layouts the
     fully-manual sharded round holds its state in (coordinator
     ``_round_sharded``: per-worker tensors are replicated over any
-    'data'/'model' axes until XLA's partial-auto partitioner can take
-    them)."""
+    'data'/'model' axes, since its ``shard_map`` is fully manual)."""
     def struct(st):
         sh = NamedSharding(mesh, P(*([None] * pod_dim), "pod"))
         return jax.ShapeDtypeStruct(st.shape, st.dtype, sharding=sh)
@@ -94,8 +94,6 @@ def _abstract_inputs(model, shape, mesh, rules=None):
 def _analyse(lowered, compiled, mesh, elapsed):
     n_dev = mesh.devices.size
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returns [dict] per device
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {
@@ -123,6 +121,7 @@ def _analyse(lowered, compiled, mesh, elapsed):
               "coll_total": None, "error": str(e)}
     return {
         "devices": int(n_dev),
+        "target_device_kind": DRYRUN_DEVICE_KIND,
         "flops_per_device": cost.get("flops"),
         "bytes_per_device": cost.get("bytes accessed"),
         "collective_bytes_per_device": coll,

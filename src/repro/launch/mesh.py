@@ -9,8 +9,13 @@ Axis convention (shared with ``repro.core.coordinator``):
   trainer state is partitioned over it via ``shard_map``
   (k % pod_size == 0), one master reduction crossing it per round.
 - ``'data'`` / ``'model'`` — ordinary GSPMD axes for sharding each worker's
-  model replica *within* a pod; the sharded coordinator leaves them in
-  ``shard_map``'s ``auto`` set.
+  model replica *within* a pod. The sharded coordinator's ``shard_map`` is
+  fully manual today, so each worker is replicated over these axes.
+
+Every mesh built here has ``Auto`` axis types: on the installed jax 0.9.0
+``jax.make_mesh`` defaults to ``Explicit`` axes, on which the logical
+sharding rules' ``with_sharding_constraint`` (``repro.nn.sharding``) is
+rejected.
 
 Production: single pod (16, 16) = 256 chips, axes ('data', 'model');
 multi-pod (2, 16, 16) = 512 chips, axes ('pod', 'data', 'model').
@@ -23,7 +28,11 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -36,7 +45,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1, pod: int = 1) -> Mesh:
@@ -50,7 +59,7 @@ def make_host_mesh(*, data: int = 1, model: int = 1, pod: int = 1) -> Mesh:
     jax initializes — that exact spelling; jax reads no other env var for
     this).
     """
-    return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((pod, data, model), ("pod", "data", "model"))
 
 
 def make_distributed_mesh(*, coordinator_address=None, num_processes: int = 1,

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.checkpoint import checkpoint
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.nn.param import init_tree, param_count
 from repro.serving.continuous import ContinuousEngine
@@ -120,6 +121,7 @@ def main(argv=None):
     ap.add_argument("--poll-every", type=int, default=8,
                     help="decode ticks between --watch polls")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

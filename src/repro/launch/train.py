@@ -64,6 +64,7 @@ from repro.configs.base import (FAILURE_SCENARIOS, MEMBERSHIP_SCENARIOS,
                                 ElasticConfig, OptimizerConfig)
 from repro.core.scenarios import (parse_membership_plan, read_trace,
                                   write_trace)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -204,6 +205,7 @@ def main(argv=None):
                          "schedule on identical data (the §VI convention)")
     ap.add_argument("--save", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     membership = args.membership_scenario
     plan = ()

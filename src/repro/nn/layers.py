@@ -218,14 +218,15 @@ def multihead_attention(
     is_causal = causal and not cross
     if (cfg.use_pallas and Sq == k.shape[1] and Sq % 128 == 0
             and cfg.hd in (64, 128) and cfg.rotary_pct == 1.0):
-        # Pallas TPU flash kernel (interpret-mode on CPU); full-seq paths
+        # Pallas TPU flash kernel (interpret-mode off the TPU); full-seq paths
+        from repro.kernels import interpret_mode
         from repro.kernels.flash_attention.ops import flash_attention_bshd
 
         out = flash_attention_bshd(
             q, k, v, causal=is_causal,
             window=cfg.sliding_window if is_causal else None,
             chunk=cfg.attention_chunk if is_causal else None,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     elif Sq >= 1024 and Sq % 512 == 0 and k.shape[1] % 512 == 0:
         # Blockwise (flash-style) path: O(block²) live memory; mandatory at
         # the assigned shapes. Skips dead blocks for SWA/chunked masks.

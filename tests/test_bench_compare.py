@@ -125,3 +125,22 @@ def test_committed_bench_files_parse_into_sections():
         timed = [k for _, _, rec in sections for k in rec
                  if k.endswith(compare.TIMING_SUFFIXES)]
         assert timed, path
+
+
+def test_benchmark_harness_exits_nonzero_when_a_section_raises(
+        monkeypatch, capsys):
+    import pytest
+
+    from benchmarks import kernels_bench, run
+
+    def broken():
+        raise RuntimeError("section blew up")
+
+    monkeypatch.setattr(kernels_bench, "bench", broken)
+    monkeypatch.setattr("repro.launch.compile_cache.enable_compile_cache",
+                        lambda: "")
+    with pytest.raises(SystemExit) as exc:
+        run.main(["--what", "kernels"])
+    assert exc.value.code not in (0, None)
+    assert "kernels,0,ERROR:RuntimeError:section blew up" in (
+        capsys.readouterr().out)

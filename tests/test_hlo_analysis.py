@@ -132,3 +132,14 @@ def test_fusion_sliced_param_charged_slice_bytes():
     # result + sliced param (not full) + s32 index
     assert c["bytes"] < full, c["bytes"]
     assert c["bytes"] >= 2 * slice_b
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.analysis.roofline import DRYRUN_DEVICE_KIND, peaks
+
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    assert peaks(DRYRUN_DEVICE_KIND) is v5e
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")

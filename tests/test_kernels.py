@@ -139,3 +139,41 @@ def test_adahessian_kernel_hessian_power():
     out_k = adahessian_step_pallas(p, g, h, m, v, cfg, 3)
     out_r = adahessian_step_ref(p, g, h, m, v, cfg, 3)
     np.testing.assert_allclose(out_k[0], out_r[0], rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# interpret-mode resolution
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_follows_backend_unless_explicit():
+    from repro.kernels import interpret_mode
+
+    assert interpret_mode() is (jax.default_backend() != "tpu")
+    assert interpret_mode(False) is False
+    assert interpret_mode(True) is True
+
+
+@pytest.mark.parametrize("module", [
+    "repro.kernels.elastic.kernel", "repro.kernels.elastic.ops",
+    "repro.kernels.adahessian.kernel", "repro.kernels.adahessian.ops",
+    "repro.kernels.flash_attention.kernel",
+    "repro.kernels.flash_attention.ops"])
+def test_kernel_entry_points_resolve_interpret_from_backend(module):
+    """No entry point defaults to the interpreter: leaving ``interpret``
+    out must give the compiled kernel on a TPU."""
+    import importlib
+    import inspect
+
+    mod = importlib.import_module(module)
+    entry = [f for f in vars(mod).values() if callable(f)
+             and getattr(f, "__module__", None) == module]
+    seen = 0
+    for f in entry:
+        try:
+            params = inspect.signature(f).parameters
+        except (TypeError, ValueError):
+            continue
+        if "interpret" in params:
+            seen += 1
+            assert params["interpret"].default is None, f.__name__
+    assert seen

@@ -1,4 +1,6 @@
 """Launcher CLIs: train.py (plain + elastic) and serve.py smoke runs."""
+import os
+
 import pytest
 
 from repro.launch import serve as serve_cli
@@ -170,3 +172,21 @@ def test_train_cli_failure_scenarios_end_to_end(capsys, scenario):
     assert "round 1" in out and "score=" in out
     if scenario == "straggler":
         assert "straggle=" in out
+
+
+def test_compile_cache_honours_env_dir(monkeypatch, tmp_path):
+    import jax
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert updates == []  # JAX reads the variable itself; no other dir set
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir", path)]

@@ -37,7 +37,7 @@ def test_production_mesh_axis_shapes(monkeypatch):
 
     calls = []
     monkeypatch.setattr(mesh_mod.jax, "make_mesh",
-                        lambda shape, axes: calls.append((shape, axes)))
+                        lambda shape, axes, **kw: calls.append((shape, axes)))
     mesh_mod.make_production_mesh()
     mesh_mod.make_production_mesh(multi_pod=True)
     assert calls[0] == ((16, 16), ("data", "model"))
@@ -49,7 +49,7 @@ def test_host_mesh_axis_shapes(monkeypatch):
 
     calls = []
     monkeypatch.setattr(mesh_mod.jax, "make_mesh",
-                        lambda shape, axes: calls.append((shape, axes)))
+                        lambda shape, axes, **kw: calls.append((shape, axes)))
     mesh_mod.make_host_mesh()
     mesh_mod.make_host_mesh(pod=4)
     mesh_mod.make_host_mesh(pod=2, data=3, model=5)

@@ -85,7 +85,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs.base import get_config, OptimizerConfig, ShapeConfig
 from repro.models.registry import build_model
 from repro.nn.param import abstract_tree
@@ -93,7 +93,8 @@ from repro.nn.sharding import tree_pspecs
 from repro.train.steps import (abstract_train_state, make_train_step,
                                train_state_pspecs)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 model = build_model(get_config("qwen3_4b", smoke=True))
 ocfg = OptimizerConfig(name="adahessian")
 shape = ShapeConfig("t", 64, 4, "train")
@@ -111,8 +112,6 @@ with mesh:
         state, batch, jax.ShapeDtypeStruct((2,), jnp.uint32))
     compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, list):  # older jax returns [dict] per device
-    ca = ca[0]
 print("COMPILED_OK", ca["flops"] > 0)
 """
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
