@@ -60,6 +60,18 @@ chunks (``on_chunk_end``), which is where the rule controller
 the records so a controller provably runs on observable telemetry only;
 each record also carries host-measured ``round_ms``/``dispatch_ms``, the
 step-time outlier signal.
+
+Tracing: each chunk's host work is annotated for ``jax.profiler`` —
+``repro.chunk`` (a step annotation numbered by the chunk's first round)
+around ``repro.build_batches``, ``repro.round_keys`` (the rounds'
+``fold_in``s and the keys' stack), ``repro.to_device`` (the input copies;
+the stack runs between them, in a ``repro.round_keys`` of its own),
+``repro.dispatch``, ``repro.fetch``, ``repro.records`` and
+``repro.observers``, each with the first round as ``chunk`` and the
+transfer counts or the retrace count as args. The host work keeps the
+order it had before it was annotated. The spans land in the same
+trace as the device's operations, on one clock, and cost about a
+microsecond each when no profiler session is active.
 """
 from __future__ import annotations
 
@@ -84,6 +96,16 @@ from repro.data.pipeline import TokenWorkerBatcher, WorkerBatcher
 from repro.data.synthetic import SyntheticImages, SyntheticTokens
 from repro.models.registry import build_model
 from repro.train.steps import init_train_state, make_train_step
+
+
+def _count_transfers(span, kind: str, tree) -> None:
+    """Give a span the transfers it makes, one per leaf of ``tree``, as
+    ``<kind>_transfers`` and ``<kind>_bytes``; only while a profiler session
+    records it, so the count costs nothing otherwise."""
+    if span.is_enabled():
+        leaves = jax.tree.leaves(tree)
+        span.set_metadata(**{f"{kind}_transfers": len(leaves),
+                             f"{kind}_bytes": sum(x.nbytes for x in leaves)})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,8 +204,9 @@ class RoundRecord:
     (cap,) per-slot mean local-phase loss (``None`` in plain mode);
     ``round_ms`` is host wall time attributed to this round (its chunk's
     wall time / rounds in the chunk) and ``dispatch_ms`` the chunk's
-    dispatch latency (jit-call return before materialization) — both are
-    chunk-grained, repeated on each record of the chunk.
+    dispatch latency: the host's time from before the inputs' host-to-device
+    copies to the jit call's return, before the metrics are materialized —
+    both are chunk-grained, repeated on each record of the chunk.
     """
 
     round: int
@@ -654,9 +677,11 @@ class ElasticSession:
         return n
 
     def _stack_batches(self, n: int):
-        rounds = [self.batcher.round_batches() for _ in range(n)]
-        return {key: np.stack([b[key] for b in rounds])
-                for key in rounds[0]}
+        with jax.profiler.TraceAnnotation("repro.build_batches",
+                                          chunk=self.round):
+            rounds = [self.batcher.round_batches() for _ in range(n)]
+            return {key: np.stack([b[key] for b in rounds])
+                    for key in rounds[0]}
 
     def _run_chunk_elastic(self, n: int) -> List[RoundRecord]:
         lo, hi = self.round, self.round + n
@@ -666,7 +691,8 @@ class ElasticSession:
             # re-partition the data before building this chunk's batches
             self._apply_membership(self._membership[lo])
         stacked = self._stack_batches(n)
-        rngs = [self._round_rng(r) for r in range(lo, hi)]
+        with jax.profiler.TraceAnnotation("repro.round_keys", chunk=lo):
+            rngs = [self._round_rng(r) for r in range(lo, hi)]
         # specialization on whole-schedule has_* keeps one trace per run
         # even when an individual chunk happens to be event-free
         straggle = sched.straggle[lo:hi] if sched.has_stragglers else None
@@ -679,42 +705,45 @@ class ElasticSession:
         active = (self._membership[lo:hi] if self._membership is not None
                   else None)
         join = self._join_rows[lo:hi] if self._join_rows is not None else None
+        host = dict(batches=stacked, fail=sched.fail[lo:hi],
+                    failed_recent=self._failed_recent[lo:hi],
+                    straggle=straggle, restart=restart, active=active,
+                    join=join, corrupt=corrupt, speed=speed)
+        if n == 1:  # round_step takes per-round leaves
+            host = jax.tree.map(lambda a: a[0], host)
+        batches = host.pop("batches")
+        if self._sharded:
+            step = (self.trainer.round_step_sharded if n == 1
+                    else self.trainer.round_chunk_sharded)
+        else:
+            step = (self.trainer.round_step if n == 1
+                    else self.trainer.round_chunk)
+        traced = self.trainer.traced
         t0 = time.perf_counter()
-        if n == 1:
+        with jax.profiler.TraceAnnotation("repro.to_device",
+                                          chunk=lo) as span:
+            _count_transfers(span, "h2d", (batches, host))
+            batches = {k: jnp.asarray(v) for k, v in batches.items()}
+            # the keys' stack (made on the device) stays between the batch
+            # copies and the schedule rows' copies, where it has always run
+            rng = rngs[0]
+            if n > 1:
+                with jax.profiler.TraceAnnotation("repro.round_keys",
+                                                  chunk=lo):
+                    rng = jnp.stack(rngs)
             inputs = RoundInputs(
-                batches={k: jnp.asarray(v[0]) for k, v in stacked.items()},
-                rng=rngs[0],
-                fail=jnp.asarray(sched.fail[lo]),
-                failed_recent=jnp.asarray(self._failed_recent[lo]),
-                straggle=None if straggle is None
-                else jnp.asarray(straggle[0]),
-                restart=None if restart is None else jnp.asarray(restart[0]),
-                active=None if active is None else jnp.asarray(active[0]),
-                join=None if join is None else jnp.asarray(join[0]),
-                corrupt=None if corrupt is None else jnp.asarray(corrupt[0]),
-                speed=None if speed is None else jnp.asarray(speed[0]))
-            step = (self.trainer.round_step_sharded if self._sharded
-                    else self.trainer.round_step)
+                batches=batches, rng=rng,
+                **{k: None if v is None else jnp.asarray(v)
+                   for k, v in host.items()})
+        with jax.profiler.TraceAnnotation("repro.dispatch",
+                                          chunk=lo) as span:
             self.state, m = step(self.state, inputs)
             t1 = time.perf_counter()
-            m = jax.tree.map(lambda x: np.asarray(x)[None], m)
-        else:
-            inputs = RoundInputs(
-                batches={k: jnp.asarray(v) for k, v in stacked.items()},
-                rng=jnp.stack(rngs),
-                fail=jnp.asarray(sched.fail[lo:hi]),
-                failed_recent=jnp.asarray(self._failed_recent[lo:hi]),
-                straggle=None if straggle is None else jnp.asarray(straggle),
-                restart=None if restart is None else jnp.asarray(restart),
-                active=None if active is None else jnp.asarray(active),
-                join=None if join is None else jnp.asarray(join),
-                corrupt=None if corrupt is None else jnp.asarray(corrupt),
-                speed=None if speed is None else jnp.asarray(speed))
-            chunk = (self.trainer.round_chunk_sharded if self._sharded
-                     else self.trainer.round_chunk)
-            self.state, m = chunk(self.state, inputs)
-            t1 = time.perf_counter()
-            m = jax.tree.map(np.asarray, m)
+            span.set_metadata(traced=self.trainer.traced - traced)
+        with jax.profiler.TraceAnnotation("repro.fetch", chunk=lo) as span:
+            _count_transfers(span, "d2h", m)
+            m = jax.tree.map(((lambda x: np.asarray(x)[None]) if n == 1
+                              else np.asarray), m)
         # materializing m above synced the chunk, so t2 - t0 is its wall
         # time; t1 - t0 is the async-dispatch latency (jit-call return)
         t2 = time.perf_counter()
@@ -724,38 +753,48 @@ class ElasticSession:
         echo = self._echo
         no_corrupt = np.zeros(self.capacity, bool)
         records = []
-        for i, r in enumerate(range(lo, hi)):
-            ev_loss = ev_acc = None
-            if r == hi - 1 and self._is_eval_round(r):
-                ev_loss, ev_acc = self.evaluate()
-            records.append(RoundRecord(
-                round=r, loss=float(m["loss"][i]),
-                u=m["u"][i], score=m["score"][i],
-                h1=m["h1"][i], h2=m["h2"][i],
-                fail=echo.fail[r], straggle=echo.straggle[r],
-                restart=echo.restart[r],
-                corrupt=(echo.corrupt[r] if echo.corrupt is not None
-                         else no_corrupt),
-                eval_loss=ev_loss, eval_acc=ev_acc,
-                active=(self._membership[r] if self._membership is not None
-                        else np.ones(self.capacity, bool)),
-                loss_w=m["loss_w"][i],
-                round_ms=round_ms, dispatch_ms=dispatch_ms,
-                **({"g_u": m["g_u"][i], "g_score": m["g_score"][i],
-                    "g_h1": m["g_h1"][i], "g_h2": m["g_h2"][i]}
-                   if "g_u" in m else {})))
+        with jax.profiler.TraceAnnotation("repro.records", chunk=lo):
+            for i, r in enumerate(range(lo, hi)):
+                ev_loss = ev_acc = None
+                if r == hi - 1 and self._is_eval_round(r):
+                    ev_loss, ev_acc = self.evaluate()
+                records.append(RoundRecord(
+                    round=r, loss=float(m["loss"][i]),
+                    u=m["u"][i], score=m["score"][i],
+                    h1=m["h1"][i], h2=m["h2"][i],
+                    fail=echo.fail[r], straggle=echo.straggle[r],
+                    restart=echo.restart[r],
+                    corrupt=(echo.corrupt[r] if echo.corrupt is not None
+                             else no_corrupt),
+                    eval_loss=ev_loss, eval_acc=ev_acc,
+                    active=(self._membership[r]
+                            if self._membership is not None
+                            else np.ones(self.capacity, bool)),
+                    loss_w=m["loss_w"][i],
+                    round_ms=round_ms, dispatch_ms=dispatch_ms,
+                    **({"g_u": m["g_u"][i], "g_score": m["g_score"][i],
+                        "g_h1": m["g_h1"][i], "g_h2": m["g_h2"][i]}
+                       if "g_u" in m else {})))
         return records
 
     def _run_chunk_plain(self, n: int) -> List[RoundRecord]:
         lo, hi = self.round, self.round + n
         stacked = self._stack_batches(n)
         # WorkerBatcher emits (τ=1, k=1, B, ...); drop the unit axes
-        xs = ({k: jnp.asarray(v[:, 0, 0]) for k, v in stacked.items()},
-              jnp.stack([self._round_rng(r) for r in range(lo, hi)]))
+        host = {k: v[:, 0, 0] for k, v in stacked.items()}
+        with jax.profiler.TraceAnnotation("repro.to_device",
+                                          chunk=lo) as span:
+            _count_transfers(span, "h2d", host)
+            batches = {k: jnp.asarray(v) for k, v in host.items()}
+        with jax.profiler.TraceAnnotation("repro.round_keys", chunk=lo):
+            rng = jnp.stack([self._round_rng(r) for r in range(lo, hi)])
         t0 = time.perf_counter()
-        self.state, m = self._plain_chunk(self.state, xs)
-        t1 = time.perf_counter()
-        loss = np.asarray(m["loss"])
+        with jax.profiler.TraceAnnotation("repro.dispatch", chunk=lo):
+            self.state, m = self._plain_chunk(self.state, (batches, rng))
+            t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("repro.fetch", chunk=lo) as span:
+            _count_transfers(span, "d2h", m["loss"])
+            loss = np.asarray(m["loss"])
         t2 = time.perf_counter()
         round_ms = (t2 - t0) * 1e3 / n
         dispatch_ms = (t1 - t0) * 1e3
@@ -763,15 +802,16 @@ class ElasticSession:
         z = np.zeros(1, np.float32)
         zb = np.zeros(1, bool)
         records = []
-        for i, r in enumerate(range(lo, hi)):
-            ev_loss = ev_acc = None
-            if r == hi - 1 and self._is_eval_round(r):
-                ev_loss, ev_acc = self.evaluate()
-            records.append(RoundRecord(
-                round=r, loss=float(loss[i]), u=z, score=z, h1=z, h2=z,
-                fail=zb, straggle=zb, restart=zb, corrupt=zb,
-                eval_loss=ev_loss, eval_acc=ev_acc, active=~zb,
-                round_ms=round_ms, dispatch_ms=dispatch_ms))
+        with jax.profiler.TraceAnnotation("repro.records", chunk=lo):
+            for i, r in enumerate(range(lo, hi)):
+                ev_loss = ev_acc = None
+                if r == hi - 1 and self._is_eval_round(r):
+                    ev_loss, ev_acc = self.evaluate()
+                records.append(RoundRecord(
+                    round=r, loss=float(loss[i]), u=z, score=z, h1=z, h2=z,
+                    fail=zb, straggle=zb, restart=zb, corrupt=zb,
+                    eval_loss=ev_loss, eval_acc=ev_acc, active=~zb,
+                    round_ms=round_ms, dispatch_ms=dispatch_ms))
         return records
 
     def run_iter(self, rounds: Optional[int] = None
@@ -788,19 +828,24 @@ class ElasticSession:
         run_chunk = (self._run_chunk_plain if self.spec.plain
                      else self._run_chunk_elastic)
         while self.round < end:
-            records = run_chunk(self._next_chunk(end))
-            # observers run before the next chunk is built: on_chunk_end is
-            # the mutation window where a controller may apply() membership
-            # edits that the following chunk then executes under
-            for obs in self._observers:
-                on_round = getattr(obs, "on_round", None)
-                if on_round is not None:
-                    for rec in records:
-                        on_round(rec)
-            for obs in self._observers:
-                on_chunk_end = getattr(obs, "on_chunk_end", None)
-                if on_chunk_end is not None:
-                    on_chunk_end(self)
+            lo, n = self.round, self._next_chunk(end)
+            with jax.profiler.StepTraceAnnotation("repro.chunk", step_num=lo,
+                                                  rounds=n):
+                records = run_chunk(n)
+                # observers run before the next chunk is built: on_chunk_end
+                # is the mutation window where a controller may apply()
+                # membership edits that the following chunk executes under
+                with jax.profiler.TraceAnnotation("repro.observers",
+                                                  chunk=lo):
+                    for obs in self._observers:
+                        on_round = getattr(obs, "on_round", None)
+                        if on_round is not None:
+                            for rec in records:
+                                on_round(rec)
+                    for obs in self._observers:
+                        on_chunk_end = getattr(obs, "on_chunk_end", None)
+                        if on_chunk_end is not None:
+                            on_chunk_end(self)
             yield from records
         if self.round >= self.spec.rounds and self.spec.save_path:
             self.save()

@@ -182,6 +182,10 @@ class ElasticTrainer:
 
     def __post_init__(self):
         self.opt = make_optimizer(self.opt_cfg)
+        # times a round program (any of the four round entry points) was
+        # traced: their Python bodies run only when JAX traces them, so a
+        # rise between two calls means the second one lowered again
+        self.traced = 0
         self._fused_local = (
             (self.use_pallas if self.fused_local is None
              else bool(self.fused_local))
@@ -792,6 +796,7 @@ class ElasticTrainer:
 
         round_new = state["round"] + 1
 
+        @jax.named_scope("global_sync")
         def global_sync(args):
             subs, mast, g_hist = args
             g_u = dw.log_distance_batched(subs, mast)
@@ -833,7 +838,13 @@ class ElasticTrainer:
         live-membership mask), then the communication phase under the fail
         mask. ``axis`` names the worker-hosting mesh axis inside
         ``shard_map`` (sharded placement); ``apply_restarts`` is per-worker
-        against the replicated master, so it needs no axis awareness."""
+        against the replicated master, so it needs no axis awareness.
+
+        The three steps run under the named scopes ``reseat``,
+        ``local_phase`` and ``comm_phase`` (``global_sync`` inside the
+        hierarchical exchange), which the compiled program carries in its
+        operations' metadata, so a device trace attributes each operation
+        to its phase."""
         reseat = inputs.restart
         if inputs.join is not None:
             # a joining slot cold-starts from the master, EASGD-style —
@@ -841,16 +852,18 @@ class ElasticTrainer:
             reseat = (inputs.join if reseat is None
                       else jnp.logical_or(reseat, inputs.join))
         if reseat is not None:
-            state = self.apply_restarts(state, reseat)
-        state, loss, loss_w = self.local_phase(state, inputs.batches,
-                                               inputs.rng, inputs.straggle,
-                                               inputs.active, axis=axis,
-                                               corrupt=inputs.corrupt,
-                                               speed=inputs.speed)
-        state, metrics = self.comm_phase(state, inputs.fail,
-                                         inputs.failed_recent,
-                                         inputs.straggle, inputs.active,
-                                         axis=axis)
+            with jax.named_scope("reseat"):
+                state = self.apply_restarts(state, reseat)
+        with jax.named_scope("local_phase"):
+            state, loss, loss_w = self.local_phase(
+                state, inputs.batches, inputs.rng, inputs.straggle,
+                inputs.active, axis=axis, corrupt=inputs.corrupt,
+                speed=inputs.speed)
+        with jax.named_scope("comm_phase"):
+            state, metrics = self.comm_phase(state, inputs.fail,
+                                             inputs.failed_recent,
+                                             inputs.straggle, inputs.active,
+                                             axis=axis)
         metrics["loss"] = loss
         metrics["loss_w"] = loss_w
         return state, metrics
@@ -864,6 +877,7 @@ class ElasticTrainer:
         of double-buffering it across calls. Don't reuse a state object
         after passing it in — keep the returned one.
         """
+        self.traced += 1
         return self._round(state, inputs)
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
@@ -875,6 +889,7 @@ class ElasticTrainer:
         bit-identical to R separate ``round_step`` calls; metrics come back
         stacked with a leading (R,) axis. ``state`` is donated, as in
         ``round_step``."""
+        self.traced += 1
         return jax.lax.scan(self._round, state, inputs)
 
     # -- sharded placement entry points -------------------------------------------
@@ -952,6 +967,7 @@ class ElasticTrainer:
         axis. Master params are bit-exact with single-device fused mode
         (tests/test_placement.py); ``state`` is donated and stays resident
         in its sharded layout across calls."""
+        self.traced += 1
         return self._round_sharded(state, inputs, chunk=False)
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
@@ -959,6 +975,7 @@ class ElasticTrainer:
         """``round_chunk`` under sharded placement: the R-round ``lax.scan``
         runs *inside* ``shard_map``, so one jit call executes R rounds with
         the worker axis on hardware and per-round collectives only."""
+        self.traced += 1
         return self._round_sharded(state, inputs, chunk=True)
 
     # -- eval ----------------------------------------------------------------------
