@@ -343,7 +343,7 @@ class ElasticTrainer:
             lambda h: spatial_average(h, self.opt_cfg.spatial_block), diag)
         return loss, grads, hs
 
-    def _fused_local_step(self, params, opt_state, batch, rngs, k_loc, axis,
+    def _fused_local_step(self, params, opt_state, batch, rngs, axis,
                           corrupt=None):
         """One τ-step for all k workers with the update batched (ISSUE-7):
         per-worker gradients + averaged Hessian diagonals, then a single
@@ -354,16 +354,7 @@ class ElasticTrainer:
         from repro.kernels import interpret_mode
         from repro.kernels.adahessian.ops import adahessian_update_batched
 
-        if axis is not None and k_loc == 1:
-            # one worker per shard: unbatched gradients, for the same
-            # singleton-vmap conv-lowering reason as the plain path below
-            sq = lambda t: jax.tree.map(lambda x: x[0], t)
-            loss, grads, hs = self._grads_one(sq(params), sq(batch), rngs[0])
-            loss = loss[None]
-            grads = jax.tree.map(lambda x: x[None], grads)
-            hs = jax.tree.map(lambda x: x[None], hs)
-        else:
-            loss, grads, hs = jax.vmap(self._grads_one)(params, batch, rngs)
+        loss, grads, hs = jax.vmap(self._grads_one)(params, batch, rngs)
         if corrupt is not None:
             # per-worker corruption on the stacked gradient trees, same
             # semantics as the plain path's in-step corruption
@@ -430,22 +421,8 @@ class ElasticTrainer:
                 rngs = jax.lax.dynamic_slice_in_dim(rngs, i0, k_loc)
             if self._fused_local:
                 new_p, new_o, loss = self._fused_local_step(
-                    params, opt_state, batch_t, rngs, k_loc, axis,
+                    params, opt_state, batch_t, rngs, axis,
                     corrupt=corrupt)
-            elif axis is not None and k_loc == 1:
-                # one worker per shard: run it unbatched. A vmap over a
-                # singleton worker axis lowers the conv weight-gradient
-                # differently from wider vmaps and breaks master bit-
-                # exactness with single placement; the unbatched gradient
-                # matches any width >= 2 bit-for-bit
-                # (tests/test_placement.py holds the line).
-                sq = lambda t: jax.tree.map(lambda x: x[0], t)
-                p1, o1, loss = self._one_step(
-                    sq(params), sq(opt_state), sq(batch_t), rngs[0],
-                    None if corrupt is None else corrupt[0])
-                new_p = jax.tree.map(lambda x: x[None], p1)
-                new_o = jax.tree.map(lambda x: x[None], o1)
-                loss = loss[None]
             elif corrupt is not None:
                 new_p, new_o, loss = jax.vmap(self._one_step)(
                     params, opt_state, batch_t, rngs, corrupt)

@@ -9,6 +9,7 @@ module-scoped fixture, never at import time: every test worker collects the
 same tests, and only the worker given this file loads the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,7 +104,10 @@ def test_flash_attention_compiles_at_qwen3_4b_heads(one_chip, window):
 def test_paper_cnn_round_chunk_compiles(one_chip, monkeypatch):
     """The whole jitted 2-round chunk of the paper's k=4, τ=2 AdaHessian
     round with both Pallas kernels: flattening and padding, scalar
-    prefetch inside ``lax.scan``, and the comm phase."""
+    prefetch inside ``lax.scan``, and the comm phase. The model's 3×3
+    convolutions are XLA's convolution at the matmul precision in force,
+    and no im2col patch tensor (24×24 outputs of conv2 by 9 taps of 32
+    channels) is built."""
     import repro.kernels
 
     # the coordinator asks interpret_mode() at trace time; this process's
@@ -127,8 +131,23 @@ def test_paper_cnn_round_chunk_compiles(one_chip, monkeypatch):
             lambda: jax.random.split(jax.random.key(0), rounds))),
         fail=_struct(one_chip, (rounds, k), jnp.bool_),
         failed_recent=_struct(one_chip, (rounds, k), jnp.bool_))
-    compiled = ElasticTrainer.round_chunk.lower(trainer, state,
-                                                inputs).compile()
+    with jax.default_matmul_precision("highest"):
+        compiled = ElasticTrainer.round_chunk.lower(trainer, state,
+                                                    inputs).compile()
     calls = "\n".join(_kernel_calls(compiled))
     assert "adahessian_update_batched" in calls
     assert "elastic_update_batched" in calls
+    hlo = compiled.as_text()
+    convs = [line for line in hlo.splitlines() if " convolution(" in line]
+    # the workers' batched dots lower to convolutions too; only the conv
+    # layers' own have a 3×3 spatial window, and carry the `conv` scope
+    # (wrapped by the transforms, as in `vmap(jvp(conv))`)
+    layers = [line for line in convs if "window={size=3x3" in line]
+    assert layers
+    for line in layers:
+        path = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "conv" in {re.sub(r"^(\w+\()+|\)+$", "", part)
+                          for part in path.split("/")}, path
+    assert all("operand_precision={highest,highest}" in line
+               for line in convs)
+    assert not re.search(r"24,24,(9,32|288)\]", hlo)
